@@ -172,6 +172,7 @@ def bench_serving(out_path: pathlib.Path) -> dict:
 
 def main() -> None:
     from benchmarks.figures import ALL
+    from repro.compile_cache import enable_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run only figures whose name contains this")
@@ -179,6 +180,7 @@ def main() -> None:
                     help="write the serving perf fingerprint to "
                     "BENCH_serving.json at the repo root and exit")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.bench_serving:
         payload = bench_serving(ROOT / "BENCH_serving.json")
         print(json.dumps(payload, indent=1))

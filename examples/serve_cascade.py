@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.config.base import DiffusionConfig, as_cascade_spec
 from repro.core.cascade import DiffusionCascade
 from repro.models.unet import init_unet
@@ -93,6 +94,7 @@ ap.add_argument("--quality-models", default=None,
 ap.add_argument("--duration", type=int, default=90)
 ap.add_argument("--seed", type=int, default=1)
 args = ap.parse_args()
+enable_compile_cache()
 
 wcs = (worker_classes_from_arg(args.worker_classes)
        if args.worker_classes else ())
@@ -175,9 +177,11 @@ bundle, profiles, fixed, control, bundle_conf = assemble_bundle(
     profiles=loaded_profiles)
 # query-agnostic bundles (Proteus) route on the bundle's random
 # confidences; the others score with the really-trained discriminator
+probe_cfg = stages[0][0]
 real_conf = lambda n: np.asarray(cascade.confidence(     # noqa: E731
     jnp.asarray(np.random.default_rng(0).normal(
-        size=(n, 16, 16, 3)).astype(np.float32))))
+        size=(n, probe_cfg.image_size, probe_cfg.image_size,
+              probe_cfg.in_channels)).astype(np.float32))))
 
 if args.mode == "cluster":
     backend = ClusterBackend(

@@ -40,6 +40,11 @@ def _disc_score(params, imgs, *, cfg, impl):
     return jax.nn.softmax(logits, -1)[:, 1]
 
 
+def _device_of(params):
+    """The device holding a weight tree (a single-device placement)."""
+    return next(iter(jax.tree.leaves(params)[0].devices()))
+
+
 @dataclasses.dataclass
 class CascadeResult:
     outputs: np.ndarray            # final images / tokens per query
@@ -78,10 +83,27 @@ class DiffusionCascade:
                 "DiffusionCascade now takes an ordered list of "
                 "(config, params) stages; wrap the light/heavy pair as "
                 "[(light_cfg, light_params), (heavy_cfg, heavy_params)]")
-        self.stages: Tuple[Stage, ...] = tuple(stages)
-        if len(self.stages) < 2:
+        stages = tuple(stages)
+        if len(stages) < 2:
             raise ValueError("a cascade needs >= 2 stages")
-        self.disc_cfg, self.disc_params = disc_cfg, disc_params
+        if latent_to_image is None:
+            # identity decode: the discriminator scores latents directly
+            bad = [cfg.name for cfg, _ in stages
+                   if cfg.in_channels != disc_cfg.in_channels]
+            if bad:
+                raise ValueError(
+                    f"discriminator takes {disc_cfg.in_channels} channels "
+                    f"but stages {bad} emit latents with other channel "
+                    "counts; give DiscriminatorConfig(in_channels=...) the "
+                    "latent channels or pass latent_to_image")
+        # weights are committed to one device: the cluster runtime places
+        # a committed copy per device, and a committed and an uncommitted
+        # call of one sampler compile two separate programs
+        dev = jax.devices()[0]
+        self.stages: Tuple[Stage, ...] = tuple(
+            (cfg, jax.device_put(params, dev)) for cfg, params in stages)
+        self.disc_cfg = disc_cfg
+        self.disc_params = jax.device_put(disc_params, dev)
         self.latent_to_image = latent_to_image or (lambda z: z)
         self.kernel_impl: Optional[str] = None
         self.batch_buckets: Tuple[int, ...] = ()
@@ -117,19 +139,26 @@ class DiffusionCascade:
         """Host-side stage fn keeping the (params, key, toks) signature:
         pads the batch to its bucket, draws the starting latent at bucket
         shape (outside jit — location does not change the values), and
-        slices outputs back to the true batch."""
+        slices outputs back to the true batch. It runs on the device that
+        holds ``params``: the tokens are committed there and the call is
+        made under that default device, so every caller reaches the
+        jitted sampler with one argument signature per device (the
+        signature is part of its cache key)."""
         def sample(params, key, toks):
-            toks = jnp.asarray(toks)
-            n = toks.shape[0]
-            m = self.bucket_for(n)
-            if m != n:
-                pad = jnp.zeros((m - n,) + tuple(toks.shape[1:]), toks.dtype)
-                toks = jnp.concatenate([toks, pad], axis=0)
-            noise = jax.random.normal(
-                key, (m, cfg.image_size, cfg.image_size, cfg.in_channels),
-                jnp.float32)
-            out = inner(params, noise, toks)
-            return out[:n] if m != n else out
+            dev = _device_of(params)
+            with jax.default_device(dev):
+                toks = jax.device_put(toks, dev)
+                n = toks.shape[0]
+                m = self.bucket_for(n)
+                if m != n:
+                    pad = jnp.zeros((m - n,) + tuple(toks.shape[1:]),
+                                    toks.dtype)
+                    toks = jnp.concatenate([toks, pad], axis=0)
+                noise = jax.random.normal(
+                    key, (m, cfg.image_size, cfg.image_size,
+                          cfg.in_channels), jnp.float32)
+                out = inner(params, noise, toks)
+                return out[:n] if m != n else out
         return sample
 
     def compile_counts(self) -> List[int]:
@@ -166,16 +195,22 @@ class DiffusionCascade:
         return [(cfg, fn, params) for (cfg, params), fn in
                 zip(self.stages, self._samplers)]
 
-    def confidence(self, images) -> np.ndarray:
-        imgs = jnp.asarray(images)
-        n = imgs.shape[0]
-        m = self.bucket_for(n)
-        if m != n:
-            pad = jnp.zeros((m - n,) + tuple(imgs.shape[1:]), imgs.dtype)
-            imgs = jnp.concatenate([imgs, pad], axis=0)
-        # GroupNorm stats are per-sample, so padded rows cannot leak into
-        # real scores; their scores are dropped here.
-        return np.asarray(self._score(self.disc_params, imgs)[:n])
+    def confidence(self, images, params=None) -> np.ndarray:
+        """P('real') per image; ``params`` overrides the discriminator
+        weights (a per-device copy of ``disc_params``)."""
+        if params is None:
+            params = self.disc_params
+        dev = _device_of(params)
+        with jax.default_device(dev):
+            imgs = jax.device_put(images, dev)
+            n = imgs.shape[0]
+            m = self.bucket_for(n)
+            if m != n:
+                pad = jnp.zeros((m - n,) + tuple(imgs.shape[1:]), imgs.dtype)
+                imgs = jnp.concatenate([imgs, pad], axis=0)
+            # GroupNorm stats are per-sample, so padded rows cannot leak
+            # into real scores; their scores are dropped here.
+            return np.asarray(self._score(params, imgs)[:n])
 
     def run_batch(self, key, prompt_tokens,
                   thresholds: Union[float, Sequence[float]]) -> CascadeResult:

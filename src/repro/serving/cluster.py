@@ -18,7 +18,6 @@ discriminator on the real tier outputs.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -101,6 +100,32 @@ class ClusterRuntime:
                         speed=class_of[i].speed if class_of[i] else 1.0,
                         wc=class_of[i])
             for i in range(serving.num_workers)]
+        # (id(params), device id) -> (params, copy committed to that
+        # device): each weight tree moves to a device once, not per call
+        self.placements: Dict[Tuple[int, int], tuple] = {}
+
+    def placed(self, params, device):
+        """``params`` committed to ``device``, transferred on first use
+        and reused by every later call there."""
+        key = (id(params), device.id)
+        hit = self.placements.get(key)
+        if hit is None:
+            # the source tree rides along so its id cannot be reused
+            hit = self.placements[key] = (params,
+                                          jax.device_put(params, device))
+        return hit[1]
+
+    def run_stage(self, sl: WorkerSlice, stage, key, toks):
+        """One stage sampler call on the slice's first device: the
+        sampler runs where the weights it is given live."""
+        _cfg, fn, params = stage
+        return fn(self.placed(params, sl.devices[0]), key, toks)
+
+    def score(self, sl: WorkerSlice, imgs) -> np.ndarray:
+        """Discriminator confidences for ``imgs`` on the slice's first
+        device."""
+        return self.cascade.confidence(
+            imgs, self.placed(self.cascade.disc_params, sl.devices[0]))
 
     def class_devices(self, class_name: str) -> tuple:
         """Devices backing the first slice of a worker class (profile
@@ -116,35 +141,34 @@ class ClusterRuntime:
         """Time each real cascade stage → per-tier LatencyProfile fits
         (tier order matches ``cascade.stages``). ``devices`` pins the
         measurement to a particular slice's hardware (per-class tables)."""
-        ctx = (jax.default_device(devices[0]) if devices
-               else contextlib.nullcontext())
         out = []
-        with ctx:
-            for cfg, fn, params in self.cascade.stage_fns():
-                ts = []
-                for b in batches:
-                    toks = jnp.zeros((b, prompt_len), jnp.int32)
-                    key = jax.random.PRNGKey(0)
-                    fn(params, key, toks).block_until_ready()  # compile warm
-                    pre = (self.cascade.compile_counts()
-                           if hasattr(self.cascade, "compile_counts")
-                           else None)
-                    best = min(_time_call(fn, params, key, toks)
-                               for _ in range(repeats))
-                    if pre is not None \
-                            and self.cascade.compile_counts() != pre:
-                        raise RuntimeError(
-                            f"stage {getattr(cfg, 'name', cfg)} recompiled "
-                            f"during timed repeats at batch {b}: the e(b) "
-                            "profile would fold compile time into service "
-                            "time")
-                    ts.append((b, best))
-                base = ts[0][1]
-                if len(ts) > 1:
-                    marg = max((ts[-1][1] - base) / (ts[-1][0] - 1), 1e-4)
-                else:
-                    marg = base * 0.5
-                out.append(LatencyProfile(base_s=base, marginal_s=marg))
+        for cfg, fn, params in self.cascade.stage_fns():
+            if devices:
+                params = self.placed(params, devices[0])
+            ts = []
+            for b in batches:
+                toks = jnp.zeros((b, prompt_len), jnp.int32)
+                key = jax.random.PRNGKey(0)
+                fn(params, key, toks).block_until_ready()  # compile warm
+                pre = (self.cascade.compile_counts()
+                       if hasattr(self.cascade, "compile_counts")
+                       else None)
+                best = min(_time_call(fn, params, key, toks)
+                           for _ in range(repeats))
+                if pre is not None \
+                        and self.cascade.compile_counts() != pre:
+                    raise RuntimeError(
+                        f"stage {getattr(cfg, 'name', cfg)} recompiled "
+                        f"during timed repeats at batch {b}: the e(b) "
+                        "profile would fold compile time into service "
+                        "time")
+                ts.append((b, best))
+            base = ts[0][1]
+            if len(ts) > 1:
+                marg = max((ts[-1][1] - base) / (ts[-1][0] - 1), 1e-4)
+            else:
+                marg = base * 0.5
+            out.append(LatencyProfile(base_s=base, marginal_s=marg))
         return out
 
     def measure_class_profiles(self, batches=(1, 2, 4), prompt_len: int = 8,
@@ -612,26 +636,25 @@ class ClusterBackend:
         slice's own devices (so per-class wall times match the per-class
         measured profiles the planner uses): returns (measured wall
         seconds, outputs)."""
-        cfg, fn, params = self._stage_fns[tier]
+        stage = self._stage_fns[tier]
         toks = jnp.zeros((batch_n, self.prompt_len), jnp.int32)
         self._key, k = jax.random.split(self._key)
-        ctx = (jax.default_device(sl.devices[0]) if sl.devices
-               else contextlib.nullcontext())
-        with ctx:
-            bucket = batch_n
-            if hasattr(self.runtime.cascade, "bucket_for"):
-                bucket = self.runtime.cascade.bucket_for(batch_n)
-            wkey = (id(fn), bucket)
-            if wkey not in self._warmed:
-                # first call at this (stage, bucket) shape compiles; keep
-                # it out of the measured wall so service times stay
-                # comparable to the planner's steady-state e(b) profile
-                fn(params, k, toks).block_until_ready()
-                self._warmed.add(wkey)
-            t0 = time.perf_counter()
-            imgs = fn(params, k, toks)
-            imgs.block_until_ready()
-            return time.perf_counter() - t0, imgs
+        bucket = batch_n
+        if hasattr(self.runtime.cascade, "bucket_for"):
+            bucket = self.runtime.cascade.bucket_for(batch_n)
+        # programs compile per device: a slice on another chip compiles
+        # its own copy of a bucket another slice already ran
+        wkey = (id(stage[1]), bucket, sl.devices[0])
+        if wkey not in self._warmed:
+            # first call at this (stage, bucket, device) compiles; keep it
+            # out of the measured wall so service times stay comparable
+            # to the planner's steady-state e(b) profile
+            self.runtime.run_stage(sl, stage, k, toks).block_until_ready()
+            self._warmed.add(wkey)
+        t0 = time.perf_counter()
+        imgs = self.runtime.run_stage(sl, stage, k, toks)
+        imgs.block_until_ready()
+        return time.perf_counter() - t0, imgs
 
     def _drain(self, t_end: float) -> None:
         """Run batches on every slice whose virtual clock is inside the
@@ -687,7 +710,7 @@ class ClusterBackend:
                 disc_wall = self.spec.tiers[tier].disc_latency_s
             else:
                 t0 = time.perf_counter()
-                confs = self.runtime.cascade.confidence(imgs)
+                confs = self.runtime.score(sl, imgs)
                 disc_wall = time.perf_counter() - t0
                 self._conf_samples[tier].extend(float(c) for c in confs)
             if self.stage_mode:

@@ -47,6 +47,8 @@ def _disc_cfg():
     ((3, 4, 4, 16), 8, False),    # attention pre-norm (no act)
     ((2, 6, 6, 10), 8, True),     # group shrink: 10 % 8 -> g=5
     ((5, 8, 24), 4, True),        # pre-flattened (B, HW, C)
+    ((2, 64, 64, 12), 8, True),   # 64x64: two HW tiles, group shrink g=6
+    ((1, 64, 64, 16), 8, False),  # 64x64: two HW tiles, no act
 ])
 def test_fused_groupnorm_parity(shape, groups, act):
     x = jax.random.normal(KEY, shape, jnp.float32)
