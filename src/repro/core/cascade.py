@@ -23,6 +23,7 @@ from repro.kernels import impls as kimpls
 from repro.models import diffusion as diff
 from repro.models.efficientnet import (DiscriminatorConfig,
                                        apply_discriminator)
+from repro.serving.spans import RECORDER
 
 Stage = Tuple[DiffusionConfig, object]        # (config, params)
 
@@ -146,18 +147,21 @@ class DiffusionCascade:
         signature is part of its cache key)."""
         def sample(params, key, toks):
             dev = _device_of(params)
-            with jax.default_device(dev):
-                toks = jax.device_put(toks, dev)
-                n = toks.shape[0]
-                m = self.bucket_for(n)
-                if m != n:
-                    pad = jnp.zeros((m - n,) + tuple(toks.shape[1:]),
-                                    toks.dtype)
-                    toks = jnp.concatenate([toks, pad], axis=0)
-                noise = jax.random.normal(
-                    key, (m, cfg.image_size, cfg.image_size,
-                          cfg.in_channels), jnp.float32)
-                out = inner(params, noise, toks)
+            n = toks.shape[0]
+            m = self.bucket_for(n)
+            with jax.default_device(dev), \
+                    RECORDER.span("sample", rows=n, bucket=m):
+                with RECORDER.span("sample.prep"):
+                    toks = jax.device_put(toks, dev)
+                    if m != n:
+                        pad = jnp.zeros((m - n,) + tuple(toks.shape[1:]),
+                                        toks.dtype)
+                        toks = jnp.concatenate([toks, pad], axis=0)
+                    noise = jax.random.normal(
+                        key, (m, cfg.image_size, cfg.image_size,
+                              cfg.in_channels), jnp.float32)
+                with RECORDER.span("sample.launch"):
+                    out = inner(params, noise, toks)
                 return out[:n] if m != n else out
         return sample
 
@@ -201,16 +205,22 @@ class DiffusionCascade:
         if params is None:
             params = self.disc_params
         dev = _device_of(params)
-        with jax.default_device(dev):
-            imgs = jax.device_put(images, dev)
-            n = imgs.shape[0]
-            m = self.bucket_for(n)
-            if m != n:
-                pad = jnp.zeros((m - n,) + tuple(imgs.shape[1:]), imgs.dtype)
-                imgs = jnp.concatenate([imgs, pad], axis=0)
+        n = images.shape[0]
+        m = self.bucket_for(n)
+        with jax.default_device(dev), \
+                RECORDER.span("score", rows=n, bucket=m):
+            with RECORDER.span("score.prep"):
+                imgs = jax.device_put(images, dev)
+                if m != n:
+                    pad = jnp.zeros((m - n,) + tuple(imgs.shape[1:]),
+                                    imgs.dtype)
+                    imgs = jnp.concatenate([imgs, pad], axis=0)
+            with RECORDER.span("score.launch"):
+                scores = self._score(params, imgs)
             # GroupNorm stats are per-sample, so padded rows cannot leak
             # into real scores; their scores are dropped here.
-            return np.asarray(self._score(params, imgs)[:n])
+            with RECORDER.span("score.fetch"):
+                return np.asarray(scores[:n])
 
     def run_batch(self, key, prompt_tokens,
                   thresholds: Union[float, Sequence[float]]) -> CascadeResult:

@@ -134,6 +134,7 @@ def init_discriminator(key, cfg: DiscriminatorConfig):
     return p
 
 
+@jax.named_scope("disc")
 def apply_discriminator(params, cfg: DiscriminatorConfig, images,
                         impl="xla"):
     """images: (B, H, W, C) in [-1, 1]. Returns (logits (B,2),

@@ -37,6 +37,7 @@ from repro.serving.admission import AcceptAllAdmission, AdmissionPolicy
 from repro.serving.controlplane import (Census, ControlDecision,
                                         ControlPlane, windowed_telemetry)
 from repro.serving.simulator import Query, SimResult
+from repro.serving.spans import RECORDER
 
 
 @dataclasses.dataclass
@@ -436,6 +437,7 @@ class ClusterBackend:
                 continue
             q.enqueued_at = q.arrival
             self.queues[q.stage].append(q)
+            RECORDER.stamp(q.qid, "submit")
 
     def poll(self) -> SimResult:
         return self.result
@@ -639,9 +641,7 @@ class ClusterBackend:
         stage = self._stage_fns[tier]
         toks = jnp.zeros((batch_n, self.prompt_len), jnp.int32)
         self._key, k = jax.random.split(self._key)
-        bucket = batch_n
-        if hasattr(self.runtime.cascade, "bucket_for"):
-            bucket = self.runtime.cascade.bucket_for(batch_n)
+        bucket = self._bucket(batch_n)
         # programs compile per device: a slice on another chip compiles
         # its own copy of a bucket another slice already ran
         wkey = (id(stage[1]), bucket, sl.devices[0])
@@ -649,12 +649,20 @@ class ClusterBackend:
             # first call at this (stage, bucket, device) compiles; keep it
             # out of the measured wall so service times stay comparable
             # to the planner's steady-state e(b) profile
-            self.runtime.run_stage(sl, stage, k, toks).block_until_ready()
+            with RECORDER.span("warm", tier=tier, bucket=bucket):
+                self.runtime.run_stage(sl, stage, k, toks) \
+                    .block_until_ready()
             self._warmed.add(wkey)
         t0 = time.perf_counter()
         imgs = self.runtime.run_stage(sl, stage, k, toks)
-        imgs.block_until_ready()
+        with RECORDER.span("sample.wait"):
+            imgs.block_until_ready()
         return time.perf_counter() - t0, imgs
+
+    def _bucket(self, n: int) -> int:
+        cascade = self.runtime.cascade
+        return cascade.bucket_for(n) if hasattr(cascade, "bucket_for") \
+            else n
 
     def _drain(self, t_end: float) -> None:
         """Run batches on every slice whose virtual clock is inside the
@@ -684,6 +692,10 @@ class ClusterBackend:
                       t_end: float) -> bool:
         q = self.queues[tier]
         cap = max(self.batches[tier], 1)
+        rec = RECORDER
+        # the ready depth the batch is formed from (read only when
+        # recording: it walks the queue)
+        ready = sum(qq.enqueued_at <= t_end for qq in q) if rec.on else 0
         # take ready queries (arrived/deferred by t_end) without letting
         # a not-yet-ready head block them: deferrals from concurrent
         # slices land in non-monotonic enqueued_at order
@@ -696,6 +708,25 @@ class ClusterBackend:
             q.appendleft(qq)
         if not batch:
             return False
+        with rec.span("batch", tier=tier, slice=sl.wid, rows=len(batch),
+                      cap=cap, ready=ready,
+                      bucket=self._bucket(len(batch))) as sp:
+            if rec.on:
+                # what the plan batched, against what the ready queue and
+                # the largest batch choice allowed
+                rec.count("rows_batched", len(batch))
+                rec.count("rows_fillable", min(ready, max(
+                    self.spec.tier_batch_choices(
+                        tier, self.serving.batch_choices))))
+                for qq in batch:
+                    rec.stamp(qq.qid, "batch", tier, sp.id, sp.start)
+            self._run_batch(sl, tier, batch)
+        return True
+
+    def _run_batch(self, sl: WorkerSlice, tier: int,
+                   batch: List[Query]) -> None:
+        """Execute a formed batch on ``sl``, score it, and route or
+        complete its queries."""
         start = max(self.busy_until[sl.wid],
                     max(b.enqueued_at for b in batch))
         wall, imgs = self._run_stage(sl, tier, len(batch))
@@ -722,30 +753,32 @@ class ClusterBackend:
             else:
                 self._route_scored(tier, batch, confs, done_t)
         else:
-            for qq in batch:
-                self.result.tier_processed[tier] += 1
-                self._complete(qq, done_t)
-        return True
+            with RECORDER.span("route", tier=tier):
+                for qq in batch:
+                    self.result.tier_processed[tier] += 1
+                    self._complete(qq, done_t)
 
     def _route_scored(self, tier: int, batch: List[Query], confs,
                       done_t: float) -> None:
         """Apply the boundary's threshold to scored outputs: keep
         (complete at this tier) or defer to tier+1 at ``done_t``."""
-        fresh = []
-        for qq, c in zip(batch, confs):
-            qq.confidence = float(c)
-            self.result.tier_processed[tier] += 1
-            if c < self.thresholds[tier]:
-                qq.stage = tier + 1
-                qq.deferred = True
-                qq.enqueued_at = done_t
-                self.result.deferred_per_boundary[tier] += 1
-                self.queues[tier + 1].append(qq)
-            else:
-                self._complete(qq, done_t)
-            fresh.append(float(c))
-        if fresh:
-            self.profiles[tier].update(fresh)   # online f(t) refresh
+        with RECORDER.span("route", tier=tier):
+            fresh = []
+            for qq, c in zip(batch, confs):
+                qq.confidence = float(c)
+                self.result.tier_processed[tier] += 1
+                if c < self.thresholds[tier]:
+                    qq.stage = tier + 1
+                    qq.deferred = True
+                    qq.enqueued_at = done_t
+                    self.result.deferred_per_boundary[tier] += 1
+                    self.queues[tier + 1].append(qq)
+                    RECORDER.stamp(qq.qid, "defer", tier + 1)
+                else:
+                    self._complete(qq, done_t)
+                fresh.append(float(c))
+            if fresh:
+                self.profiles[tier].update(fresh)   # online f(t) refresh
 
     def _drain_disc(self, t_end: float) -> bool:
         """Stage mode: drain per-boundary disc queues on the dedicated
@@ -774,6 +807,7 @@ class ClusterBackend:
             self.result.deferred += 1
         depth = q.stage / max(self.num_tiers - 1, 1)
         self._recent_depth.append((done_t, depth))
+        RECORDER.stamp(q.qid, "done", q.stage)
 
     # ---------------- the serve loop ----------------------------------
     def serve(self, control: ControlPlane, trace,
@@ -781,7 +815,13 @@ class ClusterBackend:
         """Replay ``trace`` under ``control``: one tick per control
         period, real execution in between — the full DiffServe loop
         (estimate → solve → thresholds → enact) against measured
-        profiles."""
+        profiles. While a profiler trace is being taken, the span
+        recorder (serving/spans.py) records the call if it is off."""
+        with RECORDER.follow_profiler():
+            return self._serve(control, trace, quality_model)
+
+    def _serve(self, control: ControlPlane, trace,
+               quality_model=None) -> SimResult:
         from repro.core.quality import QualityModel
         # a cascade-searching planner may only switch within the loaded
         # stage pool: drop unenactable candidates up front, so the search

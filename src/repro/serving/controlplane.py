@@ -37,6 +37,7 @@ from repro.core.confidence import DeferralProfile
 from repro.core.milp import AllocationPlan, Telemetry
 from repro.serving.admission import (AcceptAllAdmission, AdmissionPolicy,
                                      make_admission)
+from repro.serving.spans import RECORDER
 
 
 # ---------------------------------------------------------------------------
@@ -320,6 +321,11 @@ class ControlPlane:
 
     def tick(self, backend: ExecutorBackend,
              first: bool = False) -> ControlDecision:
+        with RECORDER.span("tick", first=first):
+            return self._tick(backend, first)
+
+    def _tick(self, backend: ExecutorBackend,
+              first: bool) -> ControlDecision:
         census = backend.census()
         self.scaling.on_tick(backend, census)
         if self.planner.needs_telemetry:
@@ -344,7 +350,8 @@ class ControlPlane:
                 # congestion-aware admission policy still needs queue
                 # depths to degrade against
                 tel = backend.telemetry_window()
-        plan = self.planner.plan(tel, demand)
+        with RECORDER.span("plan"):
+            plan = self.planner.plan(tel, demand)
         chosen = getattr(self.planner, "chosen_cascade", None)
         chosen_profiles = getattr(self.planner, "chosen_profiles", None)
         decision = ControlDecision(plan=plan,
@@ -354,7 +361,8 @@ class ControlPlane:
                                    cascade=chosen,
                                    profiles=tuple(chosen_profiles)
                                    if chosen_profiles is not None else None)
-        backend.apply_plan(decision)
+        with RECORDER.span("apply"):
+            backend.apply_plan(decision)
         return decision
 
     # ------- snapshot/restore (serving/faults.py) -------

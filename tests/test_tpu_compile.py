@@ -73,3 +73,23 @@ def test_flash_attention_compiles_for_v5e(one_chip, sq):
     compiled = _compile(fn, [(8, sq, 4, 128), (8, 384, 4, 128),
                              (8, 384, 4, 128)], one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_named_scopes_keep_the_kernel_names(one_chip):
+    """The UNet's named scopes change op metadata only: the kernels'
+    instructions keep the names a trace reduction matches them by."""
+    from repro.models.unet import _attn, _groupnorm
+
+    def fn(x, scale, bias, w):
+        p = {"scale": scale, "bias": bias}
+        h = _groupnorm(x, p, 8, impl="pallas")
+        ap = {"gn": p, **{k: w for k in ("wq", "wk", "wv", "wo")},
+              "ck": w[:8], "cv": w[:8]}
+        ctx = jnp.zeros((x.shape[0], 8, 8), x.dtype)
+        return _attn(ap, h, ctx, 1, impl="pallas")
+    text = _compile(fn, [(1, 16, 16, 128), (128,), (128,), (128, 128)],
+                    one_chip).as_text()
+    names = {line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines() if "tpu_custom_call" in line}
+    assert {"fused_groupnorm", "flash_attention"} <= names
+    assert "/attn/" in text and "/groupnorm/" in text
