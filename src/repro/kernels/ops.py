@@ -13,6 +13,7 @@ import jax
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as _decode
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.flash_attention import whole_key_attention as _whole_key
 from repro.kernels.fused_groupnorm import fused_groupnorm as _groupnorm
 from repro.kernels.fused_rmsnorm import fused_rmsnorm as _rmsnorm
 from repro.kernels.mamba_scan import mamba_scan as _mamba
@@ -39,6 +40,17 @@ def flash_attention(q, k, v, *, causal=True, impl="auto",
         return ref.flash_attention_ref(q, k, v, causal=causal, kv_len=kv_len)
     return _flash(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
                   kv_len=kv_len, interpret=(mode == "interpret"))
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "block_q"))
+def whole_key_attention(q, k, v, *, impl="auto", block_q=128):
+    """Non-causal attention on the whole-key schedule (see
+    ``flash_attention.attention_plan``)."""
+    mode = _resolve(impl)
+    if mode == "xla":
+        return ref.flash_attention_ref(q, k, v, causal=False)
+    return _whole_key(q, k, v, block_q=block_q,
+                      interpret=(mode == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_k"))

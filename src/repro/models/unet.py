@@ -65,30 +65,35 @@ def _attn_init(key, c, text_dim):
             "cv": _dense_init(ks[5], text_dim, c)}
 
 
-def _flash_pad(s, block=128):
-    """Sequence length after padding for the Pallas flash kernel: no-op
-    when one block covers it (block shrinks to s), else the next multiple
-    of ``block``."""
-    return s if s <= block else -(-s // block) * block
+def _pad_rows(a, rows):
+    """Pad axis 1 of a (B,S,H,D) array with zero rows up to ``rows``."""
+    s = a.shape[1]
+    return a if rows == s else jnp.pad(a, ((0, 0), (0, rows - s),
+                                           (0, 0), (0, 0)))
 
 
 def _fused_attn(qh, kh, vh, impl):
-    """Dispatch (B,S,H,D) attention through kernels.ops.flash_attention.
-    "ref" uses the fused jnp oracle unpadded; "pallas"/"interpret" pad
-    Sq/Sk to block multiples and mask the padded K/V columns via
-    ``kv_len`` (padded q rows are sliced off — they never feed outputs)."""
+    """Dispatch (B,S,H,D) attention through the kernels' schedule plan.
+    "ref" uses the fused jnp oracle unpadded. "pallas"/"interpret" take
+    the schedule ``attention_plan`` picks from the shapes: the whole-key
+    schedule runs K/V unpadded; the online fallback pads Sk to a block
+    multiple and masks the padded K/V rows via ``kv_len``. Padded q rows
+    are sliced off (they never feed outputs)."""
     from repro.kernels import ops
+    from repro.kernels.flash_attention import WHOLE_KEY, attention_plan
     if impl == "ref":
         return ops.flash_attention(qh, kh, vh, causal=False, impl="xla")
-    sq, sk = qh.shape[1], kh.shape[1]
-    sq_p, sk_p = _flash_pad(sq), _flash_pad(sk)
-    if sq_p != sq:
-        qh = jnp.pad(qh, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
-    if sk_p != sk:
-        kh = jnp.pad(kh, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
-        vh = jnp.pad(vh, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
-    out = ops.flash_attention(qh, kh, vh, causal=False, impl=impl,
-                              kv_len=sk if sk_p != sk else None)
+    sq, sk, d = qh.shape[1], kh.shape[1], qh.shape[3]
+    plan = attention_plan(False, sq, sk, d, qh.dtype.itemsize)
+    qh = _pad_rows(qh, plan.sq)
+    if plan.schedule == WHOLE_KEY:
+        out = ops.whole_key_attention(qh, kh, vh, impl=impl,
+                                      block_q=plan.block_q)
+    else:
+        out = ops.flash_attention(
+            qh, _pad_rows(kh, plan.sk), _pad_rows(vh, plan.sk),
+            causal=False, impl=impl, block_q=plan.block_q,
+            block_k=plan.block_k, kv_len=sk if plan.sk != sk else None)
     return out[:, :sq]
 
 

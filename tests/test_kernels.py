@@ -6,6 +6,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import (ONLINE, WHOLE_KEY,
+                                           WHOLE_KEY_VMEM_BUDGET,
+                                           attention_plan,
+                                           whole_key_vmem_bytes)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -20,17 +24,65 @@ def _tol(dtype):
     (1, 64, 4, 4, 32, 32, 32),     # MHA
     (2, 128, 4, 2, 32, 64, 64),    # GQA
     (1, 128, 8, 1, 16, 128, 32),   # MQA, uneven blocks
+    # bk None: the whole-key schedule, non-causal, as the UNet calls it:
+    # S pixels against S + 8 keys (pixels and prompt tokens, not a
+    # multiple of 128), query blocks smaller than S so K/V stay resident
+    # across them, the UNet's head sizes
+    (2, 256, 2, 2, 40, 64, None),
+    (1, 128, 3, 3, 80, 32, None),
+    (2, 64, 4, 2, 160, 32, None),  # GQA
 ])
 def test_flash_attention(dtype, B, S, H, KH, D, bq, bk):
     ks = jax.random.split(KEY, 3)
+    sk = S if bk else S + 8
     q = jax.random.normal(ks[0], (B, S, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, S, KH, D), dtype)
-    v = jax.random.normal(ks[2], (B, S, KH, D), dtype)
-    out = ops.flash_attention(q, k, v, impl="interpret",
-                              block_q=bq, block_k=bk)
-    want = ref.flash_attention_ref(q, k, v)
+    k = jax.random.normal(ks[1], (B, sk, KH, D), dtype)
+    v = jax.random.normal(ks[2], (B, sk, KH, D), dtype)
+    if bk:
+        out = ops.flash_attention(q, k, v, impl="interpret",
+                                  block_q=bq, block_k=bk)
+    else:
+        out = ops.whole_key_attention(q, k, v, impl="interpret", block_q=bq)
+    want = ref.flash_attention_ref(q, k, v, causal=bool(bk))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+# The ten attentions of one cascade2-sd15 UNet evaluation (heads of
+# 8): (query rows, key rows = pixels + 8 prompt tokens, head size, calls).
+SD15_ATTENTIONS = [(4096, 4104, 40, 3), (1024, 1032, 80, 3),
+                   (256, 264, 160, 3), (64, 72, 160, 1)]
+
+
+def test_attention_plan():
+    """Every cascade2-sd15 attention takes the whole-key schedule, which
+    cuts a bucket-8 evaluation's grid steps from the online schedule's
+    217,792 to 2,176; causal calls and key axes past the VMEM budget keep
+    the online 128x128 schedule, padded to block multiples."""
+    bh = 8 * 8
+    new = old = 0
+    for sq, sk, d, n in SD15_ATTENTIONS:
+        plan = attention_plan(False, sq, sk, d, 4)
+        assert plan.schedule == WHOLE_KEY
+        assert (plan.sq, plan.sk, plan.block_k) == (sq, sk, sk)
+        assert sq % plan.block_q == 0
+        assert whole_key_vmem_bytes(plan.block_q, sk, d, 4) \
+            <= WHOLE_KEY_VMEM_BUDGET
+        new += n * bh * plan.steps
+        old += n * bh * attention_plan(True, sq, sk, d, 4).steps
+    assert (old, new) == (217_792, 2_176)
+
+    causal = attention_plan(True, 4096, 4096, 40, 4)
+    assert causal == (ONLINE, 128, 128, 4096, 4096, 32 * 32)
+    # K and V alone, double-buffered, outgrow the budget
+    sk = WHOLE_KEY_VMEM_BUDGET // (4 * 128 * 4) + 8
+    long_keys = attention_plan(False, 256, sk, 40, 4)
+    sk_p = -(-sk // 128) * 128
+    assert long_keys == (ONLINE, 128, 128, 256, sk_p, 2 * sk_p // 128)
+    # one block covers a short axis: no padding on either schedule
+    assert attention_plan(True, 72, 72, 16, 4).steps == 1
+    assert attention_plan(False, 64, 68, 4, 4)[:5] == (WHOLE_KEY, 64, 68,
+                                                       64, 68)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -14,11 +14,14 @@ import pytest
 from repro.config.base import DiffusionConfig
 from repro.core.cascade import DiffusionCascade
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import (ONLINE, WHOLE_KEY,
+                                           WHOLE_KEY_VMEM_BUDGET,
+                                           attention_plan)
 from repro.kernels.impls import bucket_for
 from repro.models.efficientnet import (DiscriminatorConfig,
                                        apply_discriminator,
                                        init_discriminator)
-from repro.models.unet import apply_unet, init_unet
+from repro.models.unet import _fused_attn, apply_unet, init_unet
 from repro.serving.baselines import make_profiles
 from repro.serving.cluster import ClusterBackend, ClusterRuntime
 from repro.serving.profiles import default_serving
@@ -96,9 +99,9 @@ def test_unet_impl_parity(impl, batch):
 
 
 def test_unet_attention_padded_kv_path():
-    """image 16 + ctx 4 gives Sk=260 — not a flash-block multiple, so the
-    interpret path must take the pad-plus-kv_len-mask route and still
-    match the einsum baseline."""
+    """image 16 + ctx 4 gives Sk=260 — a multiple of neither 8 nor the
+    online schedule's 128-row block: the whole-key schedule must run it
+    unpadded and still match the einsum baseline."""
     cfg = _unet_cfg(image_size=16, attn=(16,), name="t16")
     params = init_unet(KEY, cfg)
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 16, 16, 3))
@@ -108,6 +111,26 @@ def test_unet_attention_padded_kv_path():
     out = apply_unet(params, cfg, x, t, toks, impl="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("B,Sq,Sk,H,D,schedule", [
+    (2, 256, 264, 2, 40, WHOLE_KEY),  # 64x64's kind: Sk = Sq + 8 prompt rows
+    (1, 96, 100, 3, 16, WHOLE_KEY),   # Sk not a multiple of 8
+    # K/V past the whole-key VMEM budget fall back to the online kernel
+    # with Sk padded to 128 rows and the tail masked by kv_len
+    (1, 8, WHOLE_KEY_VMEM_BUDGET // (4 * 128 * 4) + 8, 1, 8, ONLINE),
+])
+def test_fused_attn_schedules(B, Sq, Sk, H, D, schedule):
+    """``_fused_attn`` takes the schedule the plan picks from the shapes
+    and matches the oracle on either one."""
+    assert attention_plan(False, Sq, Sk, D, 4).schedule == schedule
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Sk, H, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Sk, H, D), jnp.float32)
+    out = _fused_attn(q, k, v, "interpret")
+    want = ref.flash_attention_ref(q, k, v, causal=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
 
 
 @pytest.mark.parametrize("impl", ["ref", "interpret"])

@@ -13,7 +13,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (WHOLE_KEY, attention_plan,
+                                           flash_attention,
+                                           whole_key_attention)
 from repro.kernels.fused_groupnorm import fused_groupnorm
 
 
@@ -75,6 +77,20 @@ def test_flash_attention_compiles_for_v5e(one_chip, sq):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# The whole-key schedule at cascade2-sd15's four attention shapes, batch
+# 8 and 8 heads: (query rows, pixels + 8 prompt tokens, head size), at
+# the query block the plan picks, K/V unpadded.
+@pytest.mark.parametrize("sq,sk,d", [
+    (4096, 4104, 40), (1024, 1032, 80), (256, 264, 160), (64, 72, 160)])
+def test_whole_key_attention_compiles_for_v5e(one_chip, sq, sk, d):
+    plan = attention_plan(False, sq, sk, d, 4)
+    assert plan.schedule == WHOLE_KEY
+    fn = functools.partial(whole_key_attention, block_q=plan.block_q)
+    compiled = _compile(fn, [(8, sq, 8, d), (8, sk, 8, d), (8, sk, 8, d)],
+                        one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_named_scopes_keep_the_kernel_names(one_chip):
     """The UNet's named scopes change op metadata only: the kernels'
     instructions keep the names a trace reduction matches them by."""
@@ -89,7 +105,9 @@ def test_named_scopes_keep_the_kernel_names(one_chip):
         return _attn(ap, h, ctx, 1, impl="pallas")
     text = _compile(fn, [(1, 16, 16, 128), (128,), (128,), (128, 128)],
                     one_chip).as_text()
-    names = {line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
-             for line in text.splitlines() if "tpu_custom_call" in line}
-    assert {"fused_groupnorm", "flash_attention"} <= names
+    names = [line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert {"fused_groupnorm", "flash_attention"} <= set(names)
+    # attention_roofline reads one flash_attention event per attention
+    assert names.count("flash_attention") == 1
     assert "/attn/" in text and "/groupnorm/" in text
