@@ -1,7 +1,8 @@
 """One cell of the benchmark: set-up, the measured window, the records.
 
-Set-up builds the served cascade from a configuration file (weights from
-the seed, the ``pallas`` kernel plan), measures each tier's e(b) with the
+Set-up builds the served cascade from a configuration file and each
+tier's model module (weights from the seed, the ``pallas`` kernel plan),
+measures each tier's e(b) with the
 runtime's own ``measure_profile``, pins the deferral thresholds from the
 cell's traffic, and warms every shape the window will use. The window is
 one ``ClusterBackend.serve`` call over seeded Poisson arrivals. Thin
@@ -21,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import reference
+from chipbench import reference, registry
 from chipbench.arrivals import ArrivalTrace, poisson_arrivals
 from chipbench.policy import PinnedThresholds, threshold_for_share
 
@@ -74,20 +75,6 @@ class Records:
     cache_hits: int = 0
 
 
-def diffusion_configs(config: Dict):
-    from repro.config.base import DiffusionConfig
-    m = config["unet"]
-    return [DiffusionConfig(
-        name=f"{config['name']}-{t['name']}", image_size=m["image_size"],
-        in_channels=m["in_channels"], base_channels=m["base_channels"],
-        channel_mults=tuple(m["channel_mults"]),
-        num_res_blocks=m["num_res_blocks"],
-        attn_resolutions=tuple(m["attn_resolutions"]),
-        num_heads=m["num_heads"], text_dim=m["text_dim"],
-        num_steps=t["num_steps"], dtype=config["dtype"])
-        for t in config["tiers"]]
-
-
 def discriminator_config(config: Dict):
     from repro.models.efficientnet import DiscriminatorConfig
     d = config["discriminator"]
@@ -100,21 +87,26 @@ def discriminator_config(config: Dict):
 
 @dataclasses.dataclass
 class System:
-    """The served cascade of one configuration, built once per process."""
+    """The served cascade of one configuration, built once per process.
+    ``models`` are the tiers' ``registry.Tier``s."""
     config: Dict
+    models: List[registry.Tier]
     cascade: object
     serving: object
     weights: Tuple[List[Dict], Dict]
 
     @classmethod
-    def build(cls, config: Dict, weight_seed: int,
-              kernel_impl: Optional[str] = None) -> "System":
+    def build(cls, config: Dict, models: List[registry.Tier],
+              weight_seed: int, kernel_impl: Optional[str] = None
+              ) -> "System":
         from repro.core.cascade import DiffusionCascade
         from repro.serving.profiles import default_serving
-        weights = make_weights(config, weight_seed)
+        weights = make_weights(config, weight_seed, models)
         unets, disc = weights
+        programs = [t.model.program_config(config, tier, t.m)
+                    for t, tier in zip(models, config["tiers"])]
         cascade = DiffusionCascade(
-            list(zip(diffusion_configs(config), unets)),
+            list(zip(programs, unets)),
             discriminator_config(config), disc,
             kernel_impl=kernel_impl or config["kernel_impl"],
             batch_buckets=tuple(config["batch_buckets"]))
@@ -125,7 +117,7 @@ class System:
             batch_buckets=tuple(config["batch_buckets"]),
             batch_choices=tuple(config["batch_choices"]),
             control_period_s=float(config["control_period_s"]))
-        return cls(config, cascade, serving, weights)
+        return cls(config, models, cascade, serving, weights)
 
     def release_weights(self) -> None:
         """Drop every reference to the weights, so their memory is free
@@ -149,8 +141,9 @@ class System:
         self.weights = weights
 
 
-def make_weights(config: Dict, seed: int):
-    weights = reference.init_weights(jax.random.PRNGKey(seed), config)
+def make_weights(config: Dict, seed: int, models: List[registry.Tier]):
+    weights = reference.init_weights(jax.random.PRNGKey(seed), config,
+                                     models)
     return jax.block_until_ready(weights)
 
 

@@ -26,13 +26,14 @@ the plain reference (``chipbench/reference.py``):
 from __future__ import annotations
 
 import functools
+import json
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import reference
+from chipbench import reference, registry
 
 TIER_SAMPLE = 8      # rows per tier, one reference block
 DISC_SAMPLE = 64     # scored rows per boundary, one reference block
@@ -82,26 +83,23 @@ def _pad(x, k: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _sampler(m_key: str, steps: int, dtype: str):
-    m = _MODELS[m_key]
-    return jax.jit(functools.partial(reference.ddim_sample, m=m, steps=steps,
-                                     dtype=jnp.dtype(dtype)))
+def _sampler(model, m_json: str, steps: int, dtype: str):
+    """The jitted reference DDIM of ``model`` for the description that
+    ``m_json`` holds (a JSON string, so the cache can key on it)."""
+    tier = registry.Tier(model, json.loads(m_json))
+    return jax.jit(functools.partial(reference.ddim_sample, eps=tier.eps,
+                                     steps=steps, dtype=jnp.dtype(dtype)))
 
 
 @functools.lru_cache(maxsize=None)
-def _scorer(d_key: str, dtype: str):
-    d = _MODELS[d_key]
-    return jax.jit(functools.partial(reference.confidence, d=d,
+def _scorer(d_json: str, dtype: str):
+    return jax.jit(functools.partial(reference.confidence,
+                                     d=json.loads(d_json),
                                      dtype=jnp.dtype(dtype)))
 
 
-_MODELS: Dict[str, Dict] = {}
-
-
-def _register(desc: Dict) -> str:
-    key = repr(sorted(desc.items()))
-    _MODELS[key] = desc
-    return key
+def _json(desc: Dict) -> str:
+    return json.dumps(desc, sort_keys=True)
 
 
 def rel_l1(got: np.ndarray, want: np.ndarray) -> np.ndarray:
@@ -112,13 +110,14 @@ def rel_l1(got: np.ndarray, want: np.ndarray) -> np.ndarray:
                                                    1e-30)
 
 
-def sample_inputs(calls, config: Dict, backend_seed: int, sample_seed: int):
+def sample_inputs(calls, config: Dict, backend_seed: int, sample_seed: int,
+                  models):
     """The rows to compare: per tier, the sampled (call, row) pairs with
-    their starting noise; per boundary, sampled scored rows."""
+    their starting noise; per boundary, sampled scored rows. ``models``
+    are the tiers' ``registry.Tier``s, which give one latent shape."""
     rng = np.random.default_rng(sample_seed)
     n_tiers = len(config["tiers"])
-    m = config["unet"]
-    shape = (m["image_size"], m["image_size"], m["in_channels"])
+    shape = tuple(models[0].model.latent_shape(models[0].m))
     picked = {t: _rows(calls, t, TIER_SAMPLE, rng) for t in range(n_tiers)}
     need = max([c.index for rows in picked.values() for c, _ in rows],
                default=-1) + 1
@@ -142,17 +141,18 @@ def sample_inputs(calls, config: Dict, backend_seed: int, sample_seed: int):
     return tiers, scored
 
 
-def compare_rows(inputs, weights, config: Dict, dtype: str = "float32",
-                 served: bool = True, control: str = "bfloat16"):
+def compare_rows(inputs, weights, config: Dict, models,
+                 dtype: str = "float32", served: bool = True,
+                 control: str = "bfloat16"):
     """Per tier (got, want, control) latents and per boundary (got, want)
-    confidences. ``want`` is the float32 reference and ``control`` the
-    reference computed in ``control`` precision from the same noise. With
-    ``served``, ``got`` is what the timed path produced; without, it is
-    the reference at ``dtype`` put in the program's place."""
+    confidences. ``want`` is the float32 reference of the tier's model
+    module (``models[t]``) and ``control`` that reference computed in
+    ``control`` precision from the same noise. With ``served``, ``got``
+    is what the timed path produced; without, it is the reference at
+    ``dtype`` put in the program's place."""
     tiers, scored = inputs
     unets, disc = weights
-    m_key = _register(config["unet"])
-    d_key = _register(config["discriminator"])
+    d_json = _json(config["discriminator"])
 
     def cast(tree, dt):
         return tree if dt == "float32" else \
@@ -162,9 +162,10 @@ def compare_rows(inputs, weights, config: Dict, dtype: str = "float32",
     for t, (noise, got) in sorted(tiers.items()):
         steps = config["tiers"][t]["num_steps"]
         k, x = noise.shape[0], _pad(noise, TIER_SAMPLE)
+        model, m_json = models[t].model, _json(models[t].m)
 
         def sample(dt):
-            return np.asarray(_sampler(m_key, steps, dt)(
+            return np.asarray(_sampler(model, m_json, steps, dt)(
                 cast(unets[t], dt), noise=x, tokens=toks))[:k]
         want, ctl = sample("float32"), sample(control)
         if not served:
@@ -172,9 +173,9 @@ def compare_rows(inputs, weights, config: Dict, dtype: str = "float32",
         out_t[t] = (got, want, ctl)
     for b, (imgs, got) in sorted(scored.items()):
         k, x = imgs.shape[0], _pad(jnp.asarray(imgs), DISC_SAMPLE)
-        want = np.asarray(_scorer(d_key, "float32")(disc, images=x))[:k]
+        want = np.asarray(_scorer(d_json, "float32")(disc, images=x))[:k]
         if not served:
-            got = np.asarray(_scorer(d_key, dtype)(cast(disc, dtype),
+            got = np.asarray(_scorer(d_json, dtype)(cast(disc, dtype),
                                                    images=x))[:k]
         out_b[b] = (got, want)
     return out_t, out_b
@@ -192,7 +193,7 @@ def numbers(pairs) -> Dict[str, float]:
     return out
 
 
-def checks(window, prep, weights, config: Dict, pairs=None
+def checks(window, prep, weights, config: Dict, models, pairs=None
            ) -> Dict[str, Dict]:
     """Every number compared, each with its limit. ``pairs`` are
     ``compare_rows``' output where the caller has them already."""
@@ -208,8 +209,8 @@ def checks(window, prep, weights, config: Dict, pairs=None
     if pairs is None:
         pairs = compare_rows(sample_inputs(rec.calls, config,
                                            prep.seeds.backend,
-                                           prep.seeds.sample),
-                             weights, config)
+                                           prep.seeds.sample, models),
+                             weights, config, models)
     limits = config["limits"]
     for name, v in numbers(pairs).items():
         limit = (limits["tier_err"][int(name[4:-4])]

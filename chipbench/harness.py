@@ -54,6 +54,7 @@ class Context:
     """Everything a metric reader may read."""
     cell: str
     config: Dict
+    models: List[registry.Tier]
     traffic: Dict
     setup_s: float
     window: cell.Window
@@ -118,26 +119,34 @@ class Context:
                     self.trace.host_spans, self.trace_window,
                     "chipbench.disc")]
 
+    @property
+    def image_size(self) -> int:
+        """The latents' side, which the discriminator scores."""
+        tier = self.models[0]
+        return int(tier.model.latent_shape(tier.m)[0])
+
     def groupnorm_calls(self):
         """(count, shape) of every GroupNorm kernel call the traced calls
-        ran: each sampler call runs its UNet ``steps`` times at its
-        bucket, each discriminator call once."""
-        m, d = self.config["unet"], self.config["discriminator"]
+        ran: each sampler call runs its tier's denoiser ``steps`` times at
+        its bucket, each discriminator call once."""
+        d = self.config["discriminator"]
         out = []
         for tier, bucket, _sp in self.traced_stage_calls():
+            t = self.models[tier]
             out += [(self.steps(tier), c)
-                    for c in flops.groupnorm_calls(m, bucket)]
+                    for c in t.model.groupnorm_calls(t.m, bucket)]
         for bucket, _sp in self.traced_disc_calls():
             out += [(1, c) for c in flops.discriminator_groupnorm_calls(
-                d, bucket, m["image_size"])]
+                d, bucket, self.image_size)]
         return out
 
     def attention_calls(self):
-        m = self.config["unet"]
-        return [(self.steps(tier), c)
-                for tier, bucket, _sp in self.traced_stage_calls()
-                for c in flops.attention_calls(m, bucket,
-                                               self.config["prompt_len"])]
+        out = []
+        for tier, bucket, _sp in self.traced_stage_calls():
+            t = self.models[tier]
+            out += [(self.steps(tier), c) for c in t.model.attention_calls(
+                t.m, bucket, self.config["prompt_len"])]
+        return out
 
 
 def _histogram(values) -> Dict[int, int]:
@@ -167,8 +176,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         t_start: float, bench: Optional[Dict] = None,
         require_tpu: bool = True, kernel_impl: Optional[str] = None,
         traffic_dir=None, trace_seconds: float = 4.0,
+        model_folders=(registry.MODELS,),
         out=sys.stdout, err=sys.stderr) -> Dict:
-    """Run the cell once and return the result line (also printed)."""
+    """Run the cell once and return the result line (also printed).
+    ``model_folders`` are searched in turn for each tier's model
+    module."""
     bench = bench or registry.load_benchmark()
     wl = registry.workload(bench, cell_name)
     config = registry.config(bench, wl["config"])
@@ -182,11 +194,13 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         peaks.peak_for(info["kind"])        # an unknown chip is an error
 
     seeds = cell.Seeds.from_run_seed(seed)
+    models = registry.tier_models(config, model_folders)
     counter = cell.Records()
     listener = cell.count_compiles(counter)
     try:
         t_build = time.perf_counter()
-        system = cell.System.build(config, seeds.weights, kernel_impl)
+        system = cell.System.build(config, models, seeds.weights,
+                                   kernel_impl)
         t_prep = time.perf_counter()
         prep = cell.prepare(system, traffic, seeds, seconds)
         setup_s = time.perf_counter() - t_start
@@ -213,7 +227,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         monitoring.unregister_event_listener(listener[1])
     mem = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     device = dict(info, memory_peak_bytes=mem)
-    ctx = Context(cell_name, config, traffic, setup_s, window, prep, device)
+    ctx = Context(cell_name, config, models, traffic, setup_s, window, prep,
+                  device)
     breakdown = None
     if trace_dir:
         try:
@@ -257,7 +272,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     ctx.prep = None
     prep.backend = prep.runtime = prep.control = None
     system.cascade = None
-    numbers = correct.checks(window, prep, weights, config)
+    numbers = correct.checks(window, prep, weights, config, models)
     ok = correct.passed(numbers)
     served = {q for q, _t, _l in prep.records.completions}
     line = {"correct": ok, "attempted": window.offered,

@@ -10,10 +10,11 @@ def read(ctx):
     calls = ctx.traced_stage_calls()
     if ctx.peak is None or not calls:
         return None
-    m, d = ctx.config["unet"], ctx.config["discriminator"]
-    unet = flops.unet_flops(m, 1, ctx.config["prompt_len"])
-    disc = flops.discriminator_flops(d, 1, m["image_size"])
-    useful = sum(int(sp.stat("rows")) * ctx.steps(tier) * unet
+    prompt_len = ctx.config["prompt_len"]
+    unet = [t.model.unet_flops(t.m, 1, prompt_len) for t in ctx.models]
+    disc = flops.discriminator_flops(ctx.config["discriminator"], 1,
+                                     ctx.image_size)
+    useful = sum(int(sp.stat("rows")) * ctx.steps(tier) * unet[tier]
                  for tier, _b, sp in calls)
     useful += sum(int(sp.stat("rows")) * disc
                   for _b, sp in ctx.traced_disc_calls())

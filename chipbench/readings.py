@@ -74,19 +74,24 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         seeds = cell.Seeds.from_run_seed(seed)
         if system is None:
-            system = cell.System.build(config, seeds.weights)
+            system = cell.System.build(
+                config, registry.tier_models(config), seeds.weights)
         else:
             # the last seed's weights go first: two sets need not fit
             system.release_weights()
-            system.swap_weights(cell.make_weights(config, seeds.weights))
+            system.swap_weights(cell.make_weights(config, seeds.weights,
+                                                  system.models))
         # e(b) depends on the shapes alone: measured for the first seed
         prep = cell.prepare(system, traffic, seeds, args.seconds, prof)
         prof = prep.profiles
         window = cell.run_window(system, prep)
         inputs = correct.sample_inputs(prep.records.calls, config,
-                                       seeds.backend, seeds.sample)
-        pairs = correct.compare_rows(inputs, system.weights, config)
-        nums = correct.checks(window, prep, system.weights, config, pairs)
+                                       seeds.backend, seeds.sample,
+                                       system.models)
+        pairs = correct.compare_rows(inputs, system.weights, config,
+                                     system.models)
+        nums = correct.checks(window, prep, system.weights, config,
+                              system.models, pairs)
         rec = {"seed": seed, "wall_s": window.wall_s,
                "offered": window.offered,
                "shares": cell.realized_shares(prep.records,
@@ -95,7 +100,8 @@ def main(argv=None) -> int:
         rec["program"].update(row_stats(pairs))
         if args.control:
             ctl = correct.compare_rows(inputs, system.weights, config,
-                                       dtype=args.control, served=False)
+                                       system.models, dtype=args.control,
+                                       served=False)
             rec["control"] = dict(correct.numbers(ctl), **row_stats(ctl))
         rec["seconds"] = time.perf_counter() - t0
         print(json.dumps(rec), flush=True)

@@ -1,18 +1,19 @@
-"""Plain reference of the served cascade's models, and the seeded weights.
+"""Plain reference of the served cascade, and the seeded weights.
 
-Written from the equations of the configuration (see the configuration
-files' ``architecture``), in straightforward ``jax.numpy``: no kernels, no
-batching tricks, nothing imported from the system under test. It serves
-two purposes:
+Written from the equations of the configuration, in straightforward
+``jax.numpy``: no kernels, no batching tricks, nothing imported from the
+system under test. Each tier's denoiser is a model module
+(``chipbench/models/<name>.py``), which gives its weight specs and its
+noise prediction; this file holds what every tier shares:
 
-* ``init_weights`` makes every tier's UNet and the discriminator from the
-  run's seed in one jitted call, on the device, in float32 (the type they
-  are served in). The trees have the layout the served models read.
-* ``ddim_sample`` / ``confidence`` compute what the served path should
-  produce. ``dtype=float32`` runs every matmul and convolution at
-  ``highest`` precision (the reference); ``dtype=bfloat16`` runs the whole
-  computation in bfloat16 (the control, the precision a later change
-  would be tempted to drop to).
+* ``init_weights`` makes every tier's denoiser and the discriminator from
+  the run's seed in one jitted call, on the device, in float32 (the type
+  they are served in). The trees have the layout the served models read.
+* ``ddim_sample`` (over a tier's ``eps``) / ``confidence`` compute what
+  the served path should produce. ``dtype=float32`` runs every matmul and
+  convolution at ``highest`` precision (the reference);
+  ``dtype=bfloat16`` runs the whole computation in bfloat16 (the control,
+  the precision a later change would be tempted to drop to).
 """
 from __future__ import annotations
 
@@ -25,7 +26,6 @@ import numpy as np
 from jax import lax
 
 NUM_TRAIN_STEPS = 1000
-TEXT_VOCAB = 1024
 GN_EPS = 1e-5
 GN_GROUPS = 8
 
@@ -44,15 +44,15 @@ class Normal:
         return int(np.prod(self.shape))
 
 
-def _conv_w(kh, kw, cin, cout):
+def conv_w(kh, kw, cin, cout):
     return Normal((kh, kw, cin, cout), math.sqrt(2.0 / (kh * kw * cin)))
 
 
-def _dense_w(cin, cout):
+def dense_w(cin, cout):
     return Normal((cin, cout), 1.0 / math.sqrt(cin))
 
 
-def _gn_w(c):
+def gn_w(c):
     # scale and bias away from (1, 0), so a kernel that ignores them fails
     return {"scale": Normal((c,), 0.1, 1.0), "bias": Normal((c,), 0.1)}
 
@@ -78,74 +78,11 @@ def materialize(key, specs):
     return jax.tree.unflatten(tree, out)
 
 
-def unet_specs(m: Dict) -> Dict:
-    """One UNet's weights for the model description ``m`` (a configuration
-    file's ``unet`` block), as the tree the served model reads."""
-    c0, res = m["base_channels"], m["image_size"]
-    temb_dim = 4 * c0
-    p = {"temb1": _dense_w(c0, temb_dim),
-         "temb2": _dense_w(temb_dim, temb_dim),
-         "text_embed": Normal((TEXT_VOCAB, m["text_dim"]), 0.02),
-         "in": _conv_w(3, 3, m["in_channels"], c0)}
-
-    def resblock(cin, cout):
-        b = {"gn1": _gn_w(cin), "w1": _conv_w(3, 3, cin, cout),
-             "temb": _dense_w(temb_dim, cout),
-             "gn2": _gn_w(cout), "w2": _conv_w(3, 3, cout, cout)}
-        if cin != cout:
-            b["skip"] = _conv_w(1, 1, cin, cout)
-        return b
-
-    def attn(c):
-        return {"gn": _gn_w(c),
-                **{w: _dense_w(c, c) for w in ("wq", "wk", "wv", "wo")},
-                "ck": _dense_w(m["text_dim"], c),
-                "cv": _dense_w(m["text_dim"], c)}
-
-    chans, cin, downs = [c0], c0, []
-    mults = m["channel_mults"]
-    for lvl, mult in enumerate(mults):
-        cout = c0 * mult
-        level = {"blocks": [], "attns": []}
-        for _ in range(m["num_res_blocks"]):
-            level["blocks"].append(resblock(cin, cout))
-            level["attns"].append(attn(cout) if res in m["attn_resolutions"]
-                                  else None)
-            cin = cout
-            chans.append(cin)
-        if lvl < len(mults) - 1:
-            level["down"] = _conv_w(3, 3, cin, cin)
-            chans.append(cin)
-            res //= 2
-        downs.append(level)
-    p["downs"] = downs
-    p["mid1"] = resblock(cin, cin)
-    p["mid_attn"] = attn(cin)
-    p["mid2"] = resblock(cin, cin)
-    ups = []
-    for lvl, mult in reversed(list(enumerate(mults))):
-        cout = c0 * mult
-        level = {"blocks": [], "attns": []}
-        for _ in range(m["num_res_blocks"] + 1):
-            level["blocks"].append(resblock(cin + chans.pop(), cout))
-            level["attns"].append(attn(cout) if res in m["attn_resolutions"]
-                                  else None)
-            cin = cout
-        if lvl > 0:
-            level["up"] = _conv_w(3, 3, cin, cin)
-            res *= 2
-        ups.append(level)
-    p["ups"] = ups
-    p["out_gn"] = _gn_w(cin)
-    p["out"] = _conv_w(3, 3, cin, m["in_channels"])
-    return p
-
-
 def discriminator_specs(d: Dict) -> Dict:
     """The discriminator's weights for ``d`` (a configuration file's
     ``discriminator`` block)."""
-    p = {"stem": _conv_w(3, 3, d["in_channels"], d["stem_channels"]),
-         "stem_gn": _gn_w(d["stem_channels"])}
+    p = {"stem": conv_w(3, 3, d["in_channels"], d["stem_channels"]),
+         "stem_gn": gn_w(d["stem_channels"])}
     cin = d["stem_channels"]
     for i, (c, depth, _stride, expand) in enumerate(d["stages"]):
         blocks = []
@@ -153,30 +90,31 @@ def discriminator_specs(d: Dict) -> Dict:
             ci = cin if j == 0 else c
             mid = ci * expand
             se = max(int(ci * d["se_ratio"]), 4)
-            b = {"gn0": _gn_w(ci)}
+            b = {"gn0": gn_w(ci)}
             if expand > 1:
-                b["w_exp"] = _conv_w(1, 1, ci, mid)
-                b["gn1"] = _gn_w(mid)
+                b["w_exp"] = conv_w(1, 1, ci, mid)
+                b["gn1"] = gn_w(mid)
             b["w_dw"] = Normal((3, 3, 1, mid), math.sqrt(2.0 / 9.0))
-            b["gn2"] = _gn_w(mid)
-            b["w_se1"] = _conv_w(1, 1, mid, se)
-            b["w_se2"] = _conv_w(1, 1, se, mid)
-            b["w_out"] = _conv_w(1, 1, mid, c)
-            b["gn3"] = _gn_w(c)
+            b["gn2"] = gn_w(mid)
+            b["w_se1"] = conv_w(1, 1, mid, se)
+            b["w_se2"] = conv_w(1, 1, se, mid)
+            b["w_out"] = conv_w(1, 1, mid, c)
+            b["gn3"] = gn_w(c)
             blocks.append(b)
             cin = c
         p[f"stage{i}"] = blocks
-    p["head"] = _conv_w(1, 1, cin, d["head_channels"])
-    p["head_gn"] = _gn_w(d["head_channels"])
-    p["fc"] = _dense_w(d["head_channels"], d["num_classes"])
+    p["head"] = conv_w(1, 1, cin, d["head_channels"])
+    p["head_gn"] = gn_w(d["head_channels"])
+    p["fc"] = dense_w(d["head_channels"], d["num_classes"])
     p["fc_b"] = Normal((d["num_classes"],), 0.1)
     return p
 
 
-def init_weights(seed_key, config: Dict) -> Tuple[List[Dict], Dict]:
-    """(one UNet per tier, the discriminator) from one key, in one jitted
-    call on the default device."""
-    specs = ([unet_specs(config["unet"]) for _ in config["tiers"]],
+def init_weights(seed_key, config: Dict, models) -> Tuple[List[Dict], Dict]:
+    """(one denoiser per tier, the discriminator) from one key, in one
+    jitted call on the default device. ``models`` are the tiers'
+    ``registry.Tier``s."""
+    specs = ([t.model.weight_specs(t.m) for t in models],
              discriminator_specs(config["discriminator"]))
     return jax.jit(lambda key: materialize(key, specs))(seed_key)
 
@@ -218,72 +156,6 @@ class _Math:
         return jax.nn.silu(y) if act else y
 
 
-def _temb(t, dim, f: _Math):
-    half = dim // 2
-    freqs = np.exp(-math.log(10_000) * np.arange(half) / half)
-    args = f.c(t)[:, None] * f.c(freqs)[None]
-    return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
-
-
-def _resblock(p, x, temb, f: _Math):
-    h = f.conv(f.gn(x, p["gn1"], True), p["w1"])
-    h = h + f.mm(jax.nn.silu(temb), p["temb"])[:, None, None, :]
-    h = f.conv(f.gn(h, p["gn2"], True), p["w2"])
-    return h + (f.conv(x, p["skip"]) if "skip" in p else f.c(x))
-
-
-def _attention(p, x, ctx, heads, f: _Math):
-    """Pixel self-attention with the prompt's tokens appended to the keys
-    and values: one softmax over (pixels + tokens)."""
-    b, h, w, c = x.shape
-    seq = f.gn(x, p["gn"], False).reshape(b, h * w, c)
-    q = f.mm(seq, p["wq"])
-    k = jnp.concatenate([f.mm(seq, p["wk"]), f.mm(ctx, p["ck"])], axis=1)
-    v = jnp.concatenate([f.mm(seq, p["wv"]), f.mm(ctx, p["cv"])], axis=1)
-    hd = c // heads
-
-    def split(a):
-        return a.reshape(b, -1, heads, hd).transpose(0, 2, 1, 3)
-    s = jnp.einsum("bhqd,bhkd->bhqk", split(q), split(k),
-                   precision=f.prec) / f.c(math.sqrt(hd))
-    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1),
-                     split(v), precision=f.prec)
-    out = out.transpose(0, 2, 1, 3).reshape(b, h * w, c)
-    return f.c(x) + f.mm(out, p["wo"]).reshape(b, h, w, c)
-
-
-def unet_eps(p, m: Dict, x, t, tokens, f: _Math):
-    """Epsilon prediction of one UNet evaluation."""
-    temb = _temb(t, m["base_channels"], f)
-    temb = f.mm(jax.nn.silu(f.mm(temb, p["temb1"])), p["temb2"])
-    ctx = f.c(jnp.take(p["text_embed"], tokens % TEXT_VOCAB, axis=0))
-    heads = m["num_heads"]
-    h = f.conv(x, p["in"])
-    skips = [h]
-    for level in p["downs"]:
-        for bp, ap in zip(level["blocks"], level["attns"]):
-            h = _resblock(bp, h, temb, f)
-            if ap is not None:
-                h = _attention(ap, h, ctx, heads, f)
-            skips.append(h)
-        if "down" in level:
-            h = f.conv(h, level["down"], stride=2)
-            skips.append(h)
-    h = _resblock(p["mid1"], h, temb, f)
-    h = _attention(p["mid_attn"], h, ctx, heads, f)
-    h = _resblock(p["mid2"], h, temb, f)
-    for level in p["ups"]:
-        for bp, ap in zip(level["blocks"], level["attns"]):
-            h = _resblock(bp, jnp.concatenate([h, skips.pop()], axis=-1),
-                          temb, f)
-            if ap is not None:
-                h = _attention(ap, h, ctx, heads, f)
-        if "up" in level:
-            h = jnp.repeat(jnp.repeat(h, 2, axis=1), 2, axis=2)
-            h = f.conv(h, level["up"])
-    return f.conv(f.gn(h, p["out_gn"], True), p["out"])
-
-
 def alphas_bar() -> np.ndarray:
     """Cosine schedule (Nichol & Dhariwal), clipped away from 0."""
     s = np.arange(NUM_TRAIN_STEPS + 1) / NUM_TRAIN_STEPS
@@ -299,8 +171,10 @@ def ddim_timesteps(steps: int) -> np.ndarray:
                           .astype(jnp.int32))
 
 
-def ddim_sample(p, m: Dict, noise, tokens, steps: int, dtype=jnp.float32):
-    """Deterministic DDIM (eta = 0) from ``noise``; returns float32."""
+def ddim_sample(p, eps, noise, tokens, steps: int, dtype=jnp.float32):
+    """Deterministic DDIM (eta = 0) from ``noise``; returns float32.
+    ``eps(p, x, t, tokens, f)`` is the tier's noise prediction in the
+    arithmetic ``f`` (a ``_Math``)."""
     f = _Math(dtype)
     ab = alphas_bar()
     ts = ddim_timesteps(steps)
@@ -312,9 +186,9 @@ def ddim_sample(p, m: Dict, noise, tokens, steps: int, dtype=jnp.float32):
 
     def step(i, x):
         c = f.c(coef[i])
-        eps = unet_eps(p, m, x, jnp.full((x.shape[0],), ts[i]), tokens, f)
-        x0 = jnp.clip((x - c[0] * eps) / c[1], -3.0, 3.0)
-        return c[2] * x0 + c[3] * eps
+        e = eps(p, x, jnp.full((x.shape[0],), ts[i]), tokens, f)
+        x0 = jnp.clip((x - c[0] * e) / c[1], -3.0, 3.0)
+        return c[2] * x0 + c[3] * e
     x = lax.fori_loop(0, steps, step, f.c(noise))
     return jnp.clip(x, -1.0, 1.0).astype(jnp.float32)
 

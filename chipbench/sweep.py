@@ -37,7 +37,8 @@ def main(argv=None) -> int:
     traffic = registry.traffic(wl["traffic"])
     harness.device_info(int(wl["chips"]))
     seeds = cell.Seeds.from_run_seed(args.seed)
-    system = cell.System.build(config, seeds.weights)
+    system = cell.System.build(config, registry.tier_models(config),
+                               seeds.weights)
     for rate in [float(r) for r in args.rates.split(",")]:
         t = dict(traffic, rate_qps=rate, min_queries=args.queries)
         prep = cell.prepare(system, t, seeds, 0.0)

@@ -8,7 +8,7 @@ import math
 import jax.numpy as jnp
 import pytest
 
-from chipbench import reference
+from chipbench import reference, registry
 from chipbench.tests import toybench
 
 
@@ -19,9 +19,10 @@ def _control_sample(params, noise, prompt_tokens, *, cfg, impl):
              num_res_blocks=cfg.num_res_blocks,
              attn_resolutions=list(cfg.attn_resolutions),
              num_heads=cfg.num_heads, text_dim=cfg.text_dim)
+    tier = registry.Tier(registry.model("joint_kv_unet"), m)
     return reference.ddim_sample(reference.cast_tree(params, jnp.bfloat16),
-                                 m, noise, prompt_tokens, cfg.num_steps,
-                                 dtype=jnp.bfloat16)
+                                 tier.eps, noise, prompt_tokens,
+                                 cfg.num_steps, dtype=jnp.bfloat16)
 
 
 def _state_unchanged(params, cfg, key, prompt_tokens, num_steps=None,

@@ -4,7 +4,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import flops
+from chipbench import flops, registry
+
+JOINT = registry.model("joint_kv_unet")
 
 UNET = dict(image_size=32, in_channels=4, base_channels=64,
             channel_mults=[1, 2, 4], num_res_blocks=2, attn_resolutions=[8],
@@ -35,7 +37,7 @@ def test_unet_count_matches_xla_within_two_percent():
         jax.ShapeDtypeStruct((b, 32, 32, 4), jnp.float32),
         jax.ShapeDtypeStruct((b,), jnp.int32),
         jax.ShapeDtypeStruct((b, 8), jnp.int32))
-    assert flops.unet_flops(UNET, b, 8) == pytest.approx(xla, rel=0.02)
+    assert JOINT.unet_flops(UNET, b, 8) == pytest.approx(xla, rel=0.02)
 
 
 def test_discriminator_count_is_its_convolutions():
@@ -86,9 +88,9 @@ def test_kernel_inventories_match_the_served_model():
     calls = _pallas_calls(jaxpr.jaxpr)
     gn = [c for c in calls if len(c) == 3 and c[0] == b]
     attn = [c for c in calls if len(c) == 3 and c[0] == b * 4]
-    want_gn = flops.groupnorm_calls(UNET, b)
+    want_gn = JOINT.groupnorm_calls(UNET, b)
     assert sorted(gn) == sorted((bb, p, c) for bb, p, c in want_gn)
-    want_attn = flops.attention_calls(UNET, b, 8)
+    want_attn = JOINT.attention_calls(UNET, b, 8)
     assert len(attn) == len(want_attn)
     dcfg = DiscriminatorConfig(in_channels=4)
     dparams = jax.eval_shape(lambda k: init_discriminator(k, dcfg),

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import cell, reference
+from chipbench import cell, reference, registry
 from chipbench.tests import toybench
 
 
@@ -14,14 +14,19 @@ from chipbench.tests import toybench
 def toy():
     import json
     config = json.loads((toybench.HERE / "toy3.json").read_text())
-    return config, cell.make_weights(config, 9)
+    return config, cell.make_weights(config, 9, registry.tier_models(config))
+
+
+def _program_config(config, tier):
+    t = registry.tier_models(config)[tier]
+    return t.model.program_config(config, config["tiers"][tier], t.m)
 
 
 def test_weight_trees_have_the_served_layout(toy):
     from repro.models.efficientnet import init_discriminator
     from repro.models.unet import init_unet
     config, (unets, disc) = toy
-    cfg = cell.diffusion_configs(config)[0]
+    cfg = _program_config(config, 0)
     served = jax.eval_shape(lambda k: init_unet(k, cfg),
                             jax.random.PRNGKey(0))
     assert jax.tree.structure(served) == jax.tree.structure(unets[0])
@@ -33,8 +38,9 @@ def test_weight_trees_have_the_served_layout(toy):
     assert jax.tree.map(lambda a: a.shape, dserved) == \
         jax.tree.map(lambda a: a.shape, disc)
     # one call, one seed: the same weights again, other weights elsewhere
-    again = cell.make_weights(config, 9)
-    other = cell.make_weights(config, 10)
+    models = registry.tier_models(config)
+    again = cell.make_weights(config, 9, models)
+    other = cell.make_weights(config, 10, models)
     assert np.array_equal(again[1]["fc"], disc["fc"])
     assert not np.array_equal(other[1]["fc"], disc["fc"])
 
@@ -49,12 +55,13 @@ def test_timesteps_match_the_sampler():
 def test_sampler_matches_reference(toy, tier):
     from repro.models.diffusion import ddim_sample
     config, (unets, _disc) = toy
-    cfg = cell.diffusion_configs(config)[tier]
+    cfg = _program_config(config, tier)
+    t = registry.tier_models(config)[tier]
     noise = jax.random.normal(jax.random.PRNGKey(3), (2, 8, 8, 4))
     toks = jnp.zeros((2, config["prompt_len"]), jnp.int32)
     got = ddim_sample(unets[tier], cfg, None, toks, impl="xla",
                       init_noise=noise)
-    want = reference.ddim_sample(unets[tier], config["unet"], noise, toks,
+    want = reference.ddim_sample(unets[tier], t.eps, noise, toks,
                                  cfg.num_steps)
     assert np.max(np.abs(np.asarray(got) - np.asarray(want))) < 1e-4
 
